@@ -78,7 +78,7 @@ TRANSFORMS = {
     "jax.value_and_grad", "jax.checkpoint", "jax.remat",
     "jax.custom_vjp", "jax.custom_jvp", "jax.eval_shape",
     "jax.make_jaxpr", "jax.linearize", "jax.jvp", "jax.vjp",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
     "jax.lax.scan", "jax.lax.while_loop", "jax.lax.fori_loop",
     "jax.lax.cond", "jax.lax.switch", "jax.lax.map",
     "jax.lax.associative_scan", "jax.lax.custom_root",

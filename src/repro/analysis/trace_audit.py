@@ -34,6 +34,7 @@ import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 
 from repro.analysis.rules import Finding
@@ -103,19 +104,19 @@ def _combo_tag(env_name, net, algo, precision) -> str:
 
 def _iter_subjaxprs(params):
     for v in params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             yield v
         elif isinstance(v, (tuple, list)):
             for x in v:
-                if isinstance(x, jax.core.ClosedJaxpr):
+                if isinstance(x, jex_core.ClosedJaxpr):
                     yield x.jaxpr
-                elif isinstance(x, jax.core.Jaxpr):
+                elif isinstance(x, jex_core.Jaxpr):
                     yield x
 
 
-def find_wide_dtypes(closed: "jax.core.ClosedJaxpr") -> List[str]:
+def find_wide_dtypes(closed: "jex_core.ClosedJaxpr") -> List[str]:
     """All distinct 64-bit dtypes appearing on any var in the jaxpr."""
     seen = set()
     stack = [closed.jaxpr]

@@ -57,24 +57,6 @@ def data_axis_size(mesh: Mesh) -> int:
     return n
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs,
-              check_replication: bool = True):
-    """Version-portable shard_map.
-
-    jax <= 0.4.x ships it as ``jax.experimental.shard_map.shard_map``
-    with a ``check_rep`` kwarg; newer releases promote it to
-    ``jax.shard_map`` and rename the kwarg ``check_vma``.  Callers in
-    this repo go through this wrapper so the kernel code works on both.
-    """
-    try:
-        from jax.experimental.shard_map import shard_map as _sm
-        kw = {"check_rep": check_replication}
-    except ImportError:
-        _sm = jax.shard_map
-        kw = {"check_vma": check_replication}
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def resolve(rules: Dict[str, AxisName], name: Optional[str],
             mesh: Mesh) -> AxisName:
     if name is None:

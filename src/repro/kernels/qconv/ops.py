@@ -26,33 +26,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.glue import (LANE, fit_block, on_tpu, pad_to,
+                                resolve_interpret, sublane_tile)
 from repro.kernels.qconv import qconv as _k
 from repro.kernels.qconv import ref as _ref
 
 # exact fp32 embedding of the int dot needs every channel partial sum
 # below 2^24: C * 127 * 127 <= 2^24  =>  C <= 1040
 _EXACT_F32_MAX_C = 1040
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _round_block(dim: int) -> int:
-    """Largest power-of-two block <= dim (min 8) for small test shapes."""
-    b = 8
-    while b * 2 <= min(dim, 128):
-        b *= 2
-    return b
-
-
-def _pad_axis(x, axis: int, mult: int):
-    p = (-x.shape[axis]) % mult
-    if p:
-        pads = [(0, 0)] * x.ndim
-        pads[axis] = (0, p)
-        x = jnp.pad(x, pads)
-    return x
 
 
 def _tap_views(qx, sx, kh, kw, stride, ho, wo):
@@ -113,27 +94,22 @@ def qconv2d_i8(qx: jax.Array, sx: jax.Array, qw: jax.Array,
     taps = _tap_views(qxp, sxp, kh, kw, stride, ho, wo)
 
     if kernel:
-        if interpret is None:
-            interpret = _interpret_default()
         m = bsz * ho * wo
-        bm = _round_block(m)
-        bn = _round_block(n)
+        bm = fit_block(m, _k.DEFAULT_BM, sublane_tile(jnp.int8))
+        bn = fit_block(n, _k.DEFAULT_BN, LANE)
         qxt = jnp.stack([t[0].reshape(m, c) for t in taps])
         sxt = jnp.stack([t[1].reshape(m, 1) for t in taps])
         qwt = qw.reshape(kh * kw, c, n)
-        qxt = _pad_axis(_pad_axis(qxt, 1, bm), 2, 8)
-        sxt = _pad_axis(sxt, 1, bm)
-        qwt = _pad_axis(_pad_axis(qwt, 1, 8), 2, bn)
-        swp = _pad_axis(jnp.broadcast_to(sw2, (1, n)), 1, bn)
-        bp = _pad_axis(b2, 1, bn)
-        out = _k.qconv_i8_taps_kernel(qxt, sxt, qwt, swp, bp, bm=bm,
-                                      bn=bn, fuse_relu=fuse_relu,
-                                      interpret=interpret)
+        out = _k.qconv_i8_taps_kernel(
+            pad_to(qxt, 1, bm, 8), pad_to(sxt, 1, bm),
+            pad_to(qwt, 1, 8, bn),
+            pad_to(jnp.broadcast_to(sw2, (1, n)), 1, bn),
+            pad_to(b2, 1, bn), bm=bm, bn=bn, fuse_relu=fuse_relu,
+            interpret=resolve_interpret(interpret))
         return out[:m, :n].reshape(bsz, ho, wo, n)
 
     if exact_f32 is None:
-        exact_f32 = (jax.default_backend() != "tpu"
-                     and c <= _EXACT_F32_MAX_C)
+        exact_f32 = not on_tpu() and c <= _EXACT_F32_MAX_C
     dn = (((3,), (0,)), ((), ()))
     acc = jnp.zeros((bsz, ho, wo, n), jnp.float32)
     for t, (xt, st) in enumerate(taps):

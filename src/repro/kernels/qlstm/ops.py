@@ -3,19 +3,16 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels.glue import (fit_block, pad_to, resolve_interpret,
+                                sublane_tile)
 from repro.kernels.qlstm import qlstm as _k
 from repro.kernels.qlstm import ref as _ref
 
 # VMEM budget guard for the full-stripe blocking (per-core VMEM ~ 8 MiB;
 # leave generous headroom for double buffering).
 _VMEM_BUDGET_BYTES = 4 * 1024 * 1024
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def qlstm_cell(qx, sx, qh, sh, qw, sw, qu, su, b, c, *,
@@ -28,10 +25,10 @@ def qlstm_cell(qx, sx, qh, sh, qw, sw, qu, su, b, c, *,
     state c [B, H]; int32 MACs, CORDIC gate nonlinearities
     (``n_iters`` rounds), fp32 (h', c') out.  The whole [Din + H, 4H]
     weight stripe must fit VMEM (checked; tile H or fall back to
-    qmac+vact otherwise); batch pads to a multiple of 8.
+    qmac+vact otherwise); the batch is one block up to
+    ``_k.DEFAULT_BB`` rows, else ``DEFAULT_BB``-row blocks over a
+    zero-padded batch.
     """
-    if interpret is None:
-        interpret = _interpret_default()
     B, Din = qx.shape
     H = c.shape[-1]
     footprint = (Din * 4 * H) + (H * 4 * H) + 4 * (4 * H) * 4
@@ -39,11 +36,8 @@ def qlstm_cell(qx, sx, qh, sh, qw, sw, qu, su, b, c, *,
         raise ValueError(
             f"qlstm full-stripe blocking needs {footprint} B of VMEM "
             f"(> {_VMEM_BUDGET_BYTES}); tile H or fall back to qmac+vact")
-    bb = 8
-    pb = (-B) % bb
-    if pb:
-        pad = lambda a: jnp.pad(a, ((0, pb), (0, 0)))
-        qx, qh, c = pad(qx), pad(qh), pad(c)
+    bb = fit_block(B, _k.DEFAULT_BB, sublane_tile(jnp.int8))
+    qx, qh, c = pad_to(qx, bb), pad_to(qh, bb), pad_to(c, bb)
     sx = jnp.asarray(sx, jnp.float32).reshape(1, 1)
     sh = jnp.asarray(sh, jnp.float32).reshape(1, 1)
     sw = jnp.asarray(sw, jnp.float32).reshape(1, 4 * H)
@@ -51,7 +45,8 @@ def qlstm_cell(qx, sx, qh, sh, qw, sw, qu, su, b, c, *,
     b = jnp.asarray(b, jnp.float32).reshape(1, 4 * H)
     h_new, c_new = _k.qlstm_cell_kernel(qx, sx, qh, sh, qw, sw, qu, su,
                                         b, c, n_iters=n_iters, bb=bb,
-                                        interpret=interpret)
+                                        interpret=resolve_interpret(
+                                            interpret))
     return h_new[:B], c_new[:B]
 
 

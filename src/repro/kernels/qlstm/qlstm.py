@@ -20,6 +20,8 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.vact.vact import _sigmoid_tile
 
+DEFAULT_BB = 128
+
 
 def _tanh_tile(x, n_iters):
     return 2.0 * _sigmoid_tile(2.0 * x, n_iters) - 1.0
@@ -50,7 +52,7 @@ def _qlstm_kernel(qx_ref, sx_ref, qh_ref, sh_ref, qw_ref, sw_ref,
 @functools.partial(jax.jit,
                    static_argnames=("n_iters", "bb", "interpret"))
 def qlstm_cell_kernel(qx, sx, qh, sh, qw, sw, qu, su, b, c, *,
-                      n_iters, bb=8, interpret=False):
+                      n_iters, bb=DEFAULT_BB, interpret=False):
     B, Din = qx.shape
     H = c.shape[-1]
     grid = (B // bb,)

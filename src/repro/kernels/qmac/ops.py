@@ -4,20 +4,19 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.glue import (LANE, fit_block, pad_to, resolve_interpret,
+                                sublane_tile)
 from repro.kernels.qmac import qmac as _k
 from repro.kernels.qmac import ref as _ref
 
 
-def _pad_to(x, m0, m1):
-    p0 = (-x.shape[0]) % m0
-    p1 = (-x.shape[1]) % m1
-    if p0 or p1:
-        x = jnp.pad(x, ((0, p0), (0, p1)))
-    return x
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def _blocks(m, k, n, bm, bn, bk):
+    """Default tiles: a whole axis when it fits one block, else the
+    kernel's 128-wide tiles over a zero-padded axis."""
+    bm = bm or fit_block(m, _k.DEFAULT_BM, sublane_tile(jnp.int8))
+    bn = bn or fit_block(n, _k.DEFAULT_BN, LANE)
+    bk = bk or fit_block(k, _k.DEFAULT_BK, LANE)
+    return bm, bn, bk
 
 
 def qmac_i8(qx: jax.Array, qw: jax.Array, *, bm=None, bn=None, bk=None,
@@ -26,22 +25,17 @@ def qmac_i8(qx: jax.Array, qw: jax.Array, *, bm=None, bn=None, bk=None,
 
     Dtype contract: int8 operands, int32 accumulation, int32 out (no
     epilogue).  ``bm``/``bn``/``bk`` are the M/N/K tile sizes (default:
-    largest power of two <= min(dim, 128)); any M/K/N is accepted —
+    the whole dim up to 128, else 128); any M/K/N is accepted —
     operands are zero-padded to tile multiples and the result sliced
     back.  |acc| <= K*127*128 must fit int32, i.e. K <= 131072.
     ``interpret=None`` runs the Pallas interpreter off-TPU.
     """
-    if interpret is None:
-        interpret = _interpret_default()
     m, k = qx.shape
     _, n = qw.shape
-    bm = bm or min(_k.DEFAULT_BM, _round_block(m))
-    bn = bn or min(_k.DEFAULT_BN, _round_block(n))
-    bk = bk or min(_k.DEFAULT_BK, _round_block(k))
-    qxp = _pad_to(qx, bm, bk)
-    qwp = _pad_to(qw, bk, bn)
-    out = _k.qmac_i8_kernel(qxp, qwp, bm=bm, bn=bn, bk=bk,
-                            interpret=interpret)
+    bm, bn, bk = _blocks(m, k, n, bm, bn, bk)
+    out = _k.qmac_i8_kernel(pad_to(qx, bm, bk), pad_to(qw, bk, bn),
+                            bm=bm, bn=bn, bk=bk,
+                            interpret=resolve_interpret(interpret))
     return out[:m, :n]
 
 
@@ -55,28 +49,14 @@ def qmac_i8_deq(qx, sx, qw, sw, *, bm=None, bn=None, bk=None,
     qw [K, N] int8, sw [1, N] fp32 per-out-channel scales -> [M, N].
     Blocking and padding as in :func:`qmac_i8`.
     """
-    if interpret is None:
-        interpret = _interpret_default()
     m, k = qx.shape
     _, n = qw.shape
-    bm = bm or min(_k.DEFAULT_BM, _round_block(m))
-    bn = bn or min(_k.DEFAULT_BN, _round_block(n))
-    bk = bk or min(_k.DEFAULT_BK, _round_block(k))
-    qxp = _pad_to(qx, bm, bk)
-    qwp = _pad_to(qw, bk, bn)
-    sxp = _pad_to(sx.astype(jnp.float32), bm, 1)
-    swp = _pad_to(sw.astype(jnp.float32), 1, bn)
-    out = _k.qmac_i8_deq_kernel(qxp, sxp, qwp, swp, bm=bm, bn=bn, bk=bk,
-                                interpret=interpret)
+    bm, bn, bk = _blocks(m, k, n, bm, bn, bk)
+    out = _k.qmac_i8_deq_kernel(
+        pad_to(qx, bm, bk), pad_to(sx.astype(jnp.float32), bm),
+        pad_to(qw, bk, bn), pad_to(sw.astype(jnp.float32), 1, bn),
+        bm=bm, bn=bn, bk=bk, interpret=resolve_interpret(interpret))
     return out[:m, :n]
-
-
-def _round_block(dim: int) -> int:
-    """Largest power-of-two block <= dim (min 8) for small test shapes."""
-    b = 8
-    while b * 2 <= min(dim, 128):
-        b *= 2
-    return b
 
 
 # re-export oracle for test convenience

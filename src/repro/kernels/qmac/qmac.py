@@ -9,9 +9,9 @@ epilogue so the fp32 result never costs an extra HBM round trip.
 Blocking: (bm x bk) int8 activation tile, (bk x bn) int8 weight tile,
 (bm x bn) int32 VMEM accumulator.  The K grid axis is innermost and
 sequential; the accumulator is zeroed at k==0 and flushed at the last
-k step (classic Pallas matmul pattern).  Tile sides are multiples of
-the MXU native 128 lane width; int8 sublane packing (32 rows) is
-respected by keeping bm/bk/bn multiples of 128.
+k step (classic Pallas matmul pattern).  A tile side is either the
+whole (unpadded) dim or 128, a multiple of both the 128-lane width and
+int8's 32-row sublane packing (``repro.kernels.glue.fit_block``).
 """
 from __future__ import annotations
 

@@ -6,12 +6,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.glue import (LANE, fit_block, pad_to, resolve_interpret,
+                                sublane_tile)
 from repro.kernels.vact import vact as _k
 from repro.kernels.vact import ref as _ref
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _as2d(x):
@@ -20,19 +18,12 @@ def _as2d(x):
     return x.reshape(-1, x.shape[-1]), x.shape
 
 
-def _pad2d(x, bm, bn, value=0.0):
-    p0 = (-x.shape[0]) % bm
-    p1 = (-x.shape[1]) % bn
-    if p0 or p1:
-        x = jnp.pad(x, ((0, p0), (0, p1)), constant_values=value)
-    return x
-
-
-def _blk(dim, cap):
-    b = 8
-    while b * 2 <= min(dim, cap):
-        b *= 2
-    return b
+def _tiles(x2):
+    """(rows, features) blocks of a 2-D operand: whole dims up to the
+    kernel's caps, else cap-sized blocks over a zero-padded axis."""
+    bm = fit_block(x2.shape[0], _k.DEFAULT_BM, sublane_tile(x2.dtype))
+    bn = fit_block(x2.shape[1], _k.DEFAULT_BN, LANE)
+    return bm, bn
 
 
 def vact(x: jax.Array, kind: str, n_iters: int,
@@ -42,25 +33,21 @@ def vact(x: jax.Array, kind: str, n_iters: int,
     ``kind`` is one of the CORDIC-approximated nonlinearities (tanh,
     sigmoid, softmax, ...) evaluated in ``n_iters`` shift-add rounds.
     The input is flattened to [rows, features] (last axis = features);
-    rows tile at <= 128 (and features too, except softmax whose row
-    reduction must see the whole feature axis in one block).  fp32
+    rows tile at <= 256 and features at <= 128 (except softmax, whose
+    row reduction must see the whole feature axis in one block).  fp32
     compute, fp32 out, original shape restored.
     """
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     x2, shape = _as2d(x.astype(jnp.float32))
+    bm, bn = _tiles(x2)
     if kind == "softmax":
-        bm = _blk(x2.shape[0], _k.DEFAULT_BM)
         # pad rows only; columns must stay exact for the reduction
-        xp = _pad2d(x2, bm, x2.shape[1])
-        out = _k.vact_softmax_kernel(xp, n_iters=n_iters, bm=bm,
-                                     interpret=interpret)
+        out = _k.vact_softmax_kernel(pad_to(x2, bm), n_iters=n_iters,
+                                     bm=bm, interpret=interpret)
     else:
-        bm = _blk(x2.shape[0], _k.DEFAULT_BM)
-        bn = _blk(x2.shape[1], _k.DEFAULT_BN)
-        xp = _pad2d(x2, bm, bn)
-        out = _k.vact_ew_kernel(xp, kind=kind, n_iters=n_iters, bm=bm,
-                                bn=bn, interpret=interpret)
+        out = _k.vact_ew_kernel(pad_to(x2, bm, bn), kind=kind,
+                                n_iters=n_iters, bm=bm, bn=bn,
+                                interpret=interpret)
     return out[: x2.shape[0], : x2.shape[1]].reshape(shape)
 
 
@@ -71,17 +58,14 @@ def vact_q8(qx: jax.Array, sx: jax.Array, kind: str, n_iters: int,
     Dtype contract: qx int8 with per-tensor scale ``sx`` (fp32 scalar),
     dequant + CORDIC ``kind`` + requant all inside the kernel; output
     is int8 on the fixed 1/127 grid (activations land in [-1, 1]).
-    Same [rows <= 128, features <= 128] tiling as :func:`vact`.
+    Same tiling as :func:`vact`.
     """
-    if interpret is None:
-        interpret = _interpret_default()
     x2, shape = _as2d(qx)
-    bm = _blk(x2.shape[0], _k.DEFAULT_BM)
-    bn = _blk(x2.shape[1], _k.DEFAULT_BN)
-    xp = _pad2d(x2, bm, bn)
+    bm, bn = _tiles(x2)
     s = jnp.asarray(sx, jnp.float32).reshape(1, 1)
-    out = _k.vact_ew_q8_kernel(xp, s, kind=kind, n_iters=n_iters,
-                               bm=bm, bn=bn, interpret=interpret)
+    out = _k.vact_ew_q8_kernel(pad_to(x2, bm, bn), s, kind=kind,
+                               n_iters=n_iters, bm=bm, bn=bn,
+                               interpret=resolve_interpret(interpret))
     return out[: x2.shape[0], : x2.shape[1]].reshape(shape)
 
 

@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import argparse
 
+from repro.launch.compile_cache import use_compile_cache
 # the inference layer (env stack + net reconstruction + action heads)
 # is shared with repro.serve — the historical rl_train names re-export
 from repro.rl.inference import (NETS, ON_POLICY_ALGOS,  # noqa: F401
@@ -48,6 +49,7 @@ from repro.rl.trainer import (SYNC_MODES, build_mesh,  # noqa: F401
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--algo", default="ppo",
                     choices=list(ON_POLICY_ALGOS + VALUE_ALGOS))

@@ -19,6 +19,7 @@ import os
 import time
 from typing import Optional
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs import SCHEMA, JsonlSink
 from repro.serve import (PRECISIONS, PolicyServer, check_parity,
                          load_policy, serve_episodes)
@@ -104,6 +105,7 @@ def serve_policy(ckpt_dir: str, algo: Optional[str] = None,
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--ckpt", required=True,
                     help="checkpoint dir written by rl_train --ckpt-dir")

@@ -144,15 +144,14 @@ def moe_shard_map(x, router_w, w_gate, w_up, w_down, mesh, *,
         out = weighted.reshape(-1, top_k, D).sum(axis=1)
         return out.reshape(b_loc, S, D).astype(xb.dtype)
 
-    from repro.distributed.sharding import shard_map as _sm
-    fn = _sm(body, mesh=mesh,
-             in_specs=(P(dax if dax else None, None, None),
-                       leaf_spec(router_w, rw_spec),
-                       leaf_spec(w_gate, w_in_spec),
-                       leaf_spec(w_up, w_in_spec),
-                       leaf_spec(w_down, w_out_spec)),
-             out_specs=P(dax if dax else None, None, None),
-             check_replication=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(dax if dax else None, None, None),
+                                 leaf_spec(router_w, rw_spec),
+                                 leaf_spec(w_gate, w_in_spec),
+                                 leaf_spec(w_up, w_in_spec),
+                                 leaf_spec(w_down, w_out_spec)),
+                       out_specs=P(dax if dax else None, None, None),
+                       check_vma=False)
     return fn(x, router_w, w_gate, w_up, w_down)
 
 

@@ -35,7 +35,7 @@ from repro.core.fxp import QTensor
 from repro.core.policy import QuantPolicy
 from repro.core.quantizer import (dequantize_params, quantize_params,
                                   quantized_nbytes)
-from repro.distributed.sharding import data_axes, data_axis_size, shard_map
+from repro.distributed.sharding import data_axes, data_axis_size
 from repro.rl.dists import ActionDist, distribution_for
 from repro.rl.envs.base import Environment
 from repro.rl.rollout import RolloutResult, rollout
@@ -211,13 +211,13 @@ def collect_sharded(packed, env: Environment, apply_fn: Callable,
 
     batch = P(axes)             # env axis (axis 0) over the data axes
     time_major = P(None, axes)  # trajectory leaves are [T, B, ...]
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(), P(), batch, batch),
-                   out_specs=RolloutResult(traj=time_major,
-                                           last_value=batch,
-                                           final_env=batch,
-                                           final_obs=batch),
-                   check_replication=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(), P(), batch, batch),
+                       out_specs=RolloutResult(traj=time_major,
+                                               last_value=batch,
+                                               final_env=batch,
+                                               final_obs=batch),
+                       check_vma=False)
     return fn(packed, key, env_state, obs)
 
 
@@ -296,8 +296,8 @@ def collect_value_sharded(packed, env: Environment, behave_fn: Callable,
 
     batch = P(axes)             # env axis (axis 0) over the data axes
     time_major = P(None, axes)  # trajectory leaves are [T, B, ...]
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(), batch, P(), batch, batch),
-                   out_specs=((batch, batch), (time_major,) * 6),
-                   check_replication=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(), batch, P(), batch, batch),
+                       out_specs=((batch, batch), (time_major,) * 6),
+                       check_vma=False)
     return fn(packed, keys, eps, env_state, obs)

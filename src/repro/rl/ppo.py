@@ -62,7 +62,8 @@ def ppo_loss(params, apply_fn: Callable, batch: dict, cfg: PPOConfig,
     entropy = mean(dist.entropy(dparams))
 
     loss = pg_loss + cfg.vf_coef * v_loss - cfg.ent_coef * entropy
-    stats = {"pg_loss": pg_loss, "v_loss": v_loss, "entropy": entropy,
+    stats = {"loss": loss, "pg_loss": pg_loss, "v_loss": v_loss,
+             "entropy": entropy,
              "approx_kl": mean(batch["log_probs"] - logp)}
     return loss, stats
 
@@ -84,7 +85,7 @@ def a2c_loss(params, apply_fn: Callable, batch: dict, cfg: PPOConfig,
     v_loss = 0.5 * mean(jnp.square(values - batch["returns"]))
     entropy = mean(dist.entropy(dparams))
     loss = pg_loss + cfg.vf_coef * v_loss - cfg.ent_coef * entropy
-    return loss, {"pg_loss": pg_loss, "v_loss": v_loss,
+    return loss, {"loss": loss, "pg_loss": pg_loss, "v_loss": v_loss,
                   "entropy": entropy}
 
 

@@ -49,7 +49,7 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import data_axes, shard_map
+from repro.distributed.sharding import data_axes
 from repro.obs.metrics import counter_add, gauge_max, gauge_set
 from repro.optim import adamw_update
 from repro.rl.actor_learner import (collect_sharded, collect_value,
@@ -69,7 +69,7 @@ def make_onpolicy_iteration(env, apply_fn, a_policy, mesh, dist, pcfg,
     """One sharded-collect + minibatch-update step (ppo / a2c)."""
     learner_apply = lambda p, o: apply_fn(p, o, None)  # noqa: E731
 
-    def body(params, opt, est, obs, packed, key, gmask, alive):
+    def update(params, opt, est, obs, packed, key, gmask, alive):
         k1, k2 = jax.random.split(key)
         res = collect_sharded(packed, env, apply_fn, a_policy, k1, est,
                               obs, rollout_len, mesh, dist)
@@ -88,7 +88,12 @@ def make_onpolicy_iteration(env, apply_fn, a_policy, mesh, dist, pcfg,
             k2, params, opt, batch, learner_apply, pcfg, opt_step,
             loss_fn=loss_fn, grad_mask=gmask, dist=dist)
         ret, n_ep = episode_returns(res.traj)
-        return params, opt, res.final_env, res.final_obs, ret, n_ep
+        return (params, opt, res.final_env, res.final_obs, ret,
+                n_ep), stats["loss"]
+
+    def body(params, opt, est, obs, packed, key, gmask, alive):
+        return update(params, opt, est, obs, packed, key, gmask,
+                      alive)[0]
 
     if metrics is None:
         return jax.jit(body, donate_argnums=(1, 2, 3))
@@ -96,11 +101,12 @@ def make_onpolicy_iteration(env, apply_fn, a_policy, mesh, dist, pcfg,
     @partial(jax.jit, donate_argnums=(1, 2, 3, 8))
     def iteration(params, opt, est, obs, packed, key, gmask, alive,
                   mbuf):
-        params, opt, est, obs, ret, n_ep = body(
+        (params, opt, est, obs, ret, n_ep), loss = update(
             params, opt, est, obs, packed, key, gmask, alive)
         mbuf = counter_add(mbuf, "env_steps", rollout_len * n_envs)
         mbuf = counter_add(mbuf, "episodes", n_ep)
         mbuf = gauge_set(mbuf, "return_mean", ret)
+        mbuf = gauge_set(mbuf, "loss", loss)
         mbuf = gauge_set(mbuf, "alive_frac",
                          jnp.mean(alive.astype(jnp.float32)))
         return params, opt, est, obs, ret, n_ep, mbuf
@@ -315,12 +321,12 @@ def make_sharded_value_iteration(env, agent, srb, a_policy, sched, ocfg,
         buf = jax.tree.map(lambda x: x[None], lbuf)
         return params, target, opt, buf
 
-    update_fn = shard_map(
+    update_fn = jax.shard_map(
         update_shard, mesh=mesh,
         in_specs=(P(), P(), P(), batch_spec, batch_spec, P(), P(),
                   batch_spec),
         out_specs=(P(), P(), P(), batch_spec),
-        check_replication=False)
+        check_vma=False)
 
     def body(params, target, opt, buf, packed, est, obs, key, it,
              alive):

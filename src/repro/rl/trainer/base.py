@@ -35,9 +35,10 @@ import time
 from typing import Optional
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
-from repro.distributed.sharding import data_axis_size
+from repro.distributed.sharding import data_axes, data_axis_size
 from repro.launch.mesh import (describe, make_host_mesh,
                                make_production_mesh)
 from repro.obs import (Console, MetricSpec, ProfileWindow,
@@ -197,6 +198,22 @@ class Trainer:
         pass
 
     # ---- the one driver --------------------------------------------------
+    def place(self, state: TrainState) -> TrainState:
+        """Lay the state out on the mesh as the jitted iteration
+        returns it — env state and the slot-major replay sharded over
+        the data axes, the rest replicated — so iteration 1 reuses
+        iteration 0's program instead of tracing and compiling it
+        again.  Without a mesh the state stays where it is."""
+        mesh = getattr(self, "mesh", None)
+        if mesh is None:
+            return state
+        rep = NamedSharding(mesh, P())
+        slots = NamedSharding(mesh, P(data_axes(mesh) or None))
+        put = jax.device_put
+        return TrainState(put(state.params, rep), put(state.target, rep),
+                          put(state.opt, rep), put(state.replay, slots),
+                          put(state.est, slots), put(state.obs, slots))
+
     def restore(self, mgr: CheckpointManager, state: TrainState):
         """Schema-dispatched restore: flags are validated against the
         sidecar metadata first; ``trainstate/v1`` checkpoints restore
@@ -228,6 +245,7 @@ class Trainer:
                 state, md = self.restore(mgr, state)
                 start = self.resume_start(md)
                 con.info(self.resume_message(md, state, start))
+        state = self.place(state)
         tel = None
         self.metrics = None
         if self.metrics_dir:

@@ -157,7 +157,7 @@ class OnPolicyTrainer(Trainer):
 
     def metric_spec(self) -> MetricSpec:
         return MetricSpec(counters=("env_steps", "episodes"),
-                          gauges=("return_mean", "alive_frac"))
+                          gauges=("return_mean", "alive_frac", "loss"))
 
     def run_meta(self) -> dict:
         meta = super().run_meta()

@@ -241,8 +241,7 @@ def test_qf902_real_quantize_params_is_on_grid():
 
 
 def test_qf901_wide_dtype_walk():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) * 2.0)(jnp.ones(3))
     assert ta.find_wide_dtypes(closed) == ["float64"]

@@ -90,7 +90,7 @@ def test_rl_train_forced_8dev_subprocess():
     )
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               JAX_PLATFORM_NAME="cpu",
+               JAX_PLATFORMS="cpu",
                PYTHONPATH="src" + os.pathsep
                + os.environ.get("PYTHONPATH", ""))
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -123,7 +123,7 @@ def test_value_train_forced_8dev_subprocess():
     )
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               JAX_PLATFORM_NAME="cpu",
+               JAX_PLATFORMS="cpu",
                PYTHONPATH="src" + os.pathsep
                + os.environ.get("PYTHONPATH", ""))
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

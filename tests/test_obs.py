@@ -243,6 +243,7 @@ def test_onpolicy_train_bitwise_parity(tmp_path):
     assert total == kw["iters"] * kw["n_envs"] * kw["rollout_len"]
     assert "alive_frac" in steps[-1]["metrics"]
     assert "sync_payload_bytes" in steps[-1]["metrics"]
+    assert all(np.isfinite(r["metrics"]["loss"]) for r in steps)
 
 
 def test_sharded_value_train_bitwise_parity(tmp_path):

@@ -12,6 +12,7 @@ import pytest
 from repro.core.fxp import QTensor
 from repro.core.policy import get_policy
 from repro.core.quantizer import quantize_params
+from repro.kernels.glue import resolve_interpret
 from repro.kernels.qconv import ops, ref
 from repro.nn.conv import conv2d_apply, conv2d_init, qconv_block
 from repro.nn.module import unbox
@@ -82,7 +83,8 @@ def test_pallas_kernel_matches_taps(shape, fuse_relu):
 
 def test_kernel_interpret_fallback_on_cpu():
     """interpret=None resolves to interpreter mode off-TPU."""
-    assert ops._interpret_default() == (jax.default_backend() != "tpu")
+    assert resolve_interpret(None) == (jax.default_backend() != "tpu")
+    assert resolve_interpret(False) is False
     qx, sx, qw, sw, b = _quantized_operands(SHAPES[0], seed=3)
     out = ops.qconv2d_i8(qx, sx, qw, sw, b, stride=2, kernel=True,
                          interpret=None)
