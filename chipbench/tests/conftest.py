@@ -1,0 +1,10 @@
+"""The benchmark's own tests run on the CPU at small sizes: they steer
+the drivers' functions directly and pass the CPU devices in."""
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+BENCH = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(BENCH))
+sys.path.insert(0, str(BENCH.parent / "src"))
