@@ -1,8 +1,11 @@
 """Structured telemetry: jit-safe metrics, spans, JSONL sinks.
 
 See docs/observability.md for the metric taxonomy, the JSONL schema
-and the metrics-don't-perturb-training contract.
+and the metrics-don't-perturb-training contract.  Importing this
+package registers the process's compile counter
+(:mod:`repro.obs.compiles`).
 """
+from repro.obs import compiles
 from repro.obs.console import Console, fmt_metrics           # noqa: F401
 from repro.obs.hist import (FixedHistogram,                  # noqa: F401
                             LATENCY_EDGES_S, log_edges)
@@ -14,7 +17,8 @@ from repro.obs.runlog import RunTelemetry                    # noqa: F401
 from repro.obs.sink import (KINDS, SCHEMA, JsonlSink,        # noqa: F401
                             iter_records, read_records,
                             validate_record)
-from repro.obs.spans import (SERVE_PHASES, TRAIN_PHASES,     # noqa: F401
-                             SpanClock)
+from repro.obs.spans import SpanClock                        # noqa: F401
 from repro.obs.summary import (render, summarize,            # noqa: F401
                                summarize_file)
+
+compiles.install()

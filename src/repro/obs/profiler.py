@@ -6,7 +6,9 @@ later (or at ``stop()``, whichever comes first).  The trainer calls
 ``tick(g)`` once per iteration from host code; the window is inclusive
 of ``start`` and captures exactly the jitted programs dispatched in
 between, which is the supported way to see inside the fused
-collect+learn step that wall-clock spans cannot split.
+collect+learn step: its device ops carry their phase's named scope
+(``rollout/...``, ``learner/...``) and the host spans are annotations
+on the same clock.
 
 The capture is TensorBoard-loadable (``tensorboard --logdir <dir>``)
 or openable with ``xprof``.  A ``profile`` record is reported through

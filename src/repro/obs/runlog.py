@@ -3,11 +3,12 @@
 Binds together the pieces the training/serving loop needs — a
 :class:`~repro.obs.sink.JsonlSink`, a
 :class:`~repro.obs.spans.SpanClock` and the step-window bookkeeping —
-behind three calls: ``span(name)`` around host phases, ``step_flush``
-at each log window, ``profile`` when a trace capture closes.  Windows
-are half-open ``[g0, g1)`` global-step ranges and stay contiguous
-across checkpoint resume because the sink appends and the first
-window starts at the resume step (the constructor's ``start``).
+behind two calls: ``step_flush`` at each log window, which drains the
+clock's host phases into the record, and ``profile`` when a trace
+capture closes.  Windows are half-open ``[g0, g1)`` global-step
+ranges and stay contiguous across checkpoint resume because the sink
+appends and the first window starts at the resume step (the
+constructor's ``start``).
 """
 from __future__ import annotations
 
@@ -21,14 +22,12 @@ from repro.obs.spans import SpanClock
 
 class RunTelemetry:
     def __init__(self, metrics_dir: str, *, run: Dict,
-                 name: str = "train", start: int = 0):
+                 clock: SpanClock, name: str = "train",
+                 start: int = 0):
         self.path = os.path.join(metrics_dir, f"{name}.jsonl")
         self.sink = JsonlSink(self.path, run=run)
-        self.clock = SpanClock()
+        self.clock = clock
         self._g0 = int(start)
-
-    def span(self, phase: str):
-        return self.clock(phase)
 
     def step_flush(self, g: int, metrics: Dict,
                    hists: Optional[Dict] = None) -> Dict:

@@ -68,7 +68,8 @@ def summarize(records: List[Dict], name: str = "") -> List[Row]:
         for k in ("env_steps", "episodes"):
             if k in m:
                 fields[k] = int(m[k])
-        wall = sum(spans.values())
+        # nested phases (``sync/pack``) lie inside their top level
+        wall = sum(v for k, v in spans.items() if "/" not in k)
         if "env_steps" in m and wall > 0:
             fields["steps_per_s"] = round(m["env_steps"] / wall, 1)
         last = steps[-1]["metrics"]

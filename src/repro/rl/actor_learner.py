@@ -133,7 +133,8 @@ def collect(packed, env: Environment, apply_fn: Callable,
             env_state, obs, n_steps: int,
             dist: Optional[ActionDist] = None) -> RolloutResult:
     """One actor's contribution: dequantize the synced weights, roll."""
-    params = unpack_weights(packed)
+    with jax.named_scope("actor"):
+        params = unpack_weights(packed)
     fn = (lambda p, o: apply_fn(p, o, actor_policy))
     return rollout(params, env, fn, key, env_state, obs, n_steps, dist)
 
@@ -254,12 +255,15 @@ def collect_value(packed, env: Environment, behave_fn: Callable,
     [T, B, ...] trajectory leaves — the exact scan the value iteration
     ran inline before this was extracted, bit for bit.
     """
-    actor_params = unpack_weights(packed)
+    with jax.named_scope("actor"):
+        actor_params = unpack_weights(packed)
 
     def one_full(carry, k):
         est, o = carry
-        a = behave_fn(actor_params, o, k, eps, actor_policy)
-        est, nxt, r, d, tr, fo = jax.vmap(env.step)(est, a)
+        with jax.named_scope("actor"):
+            a = behave_fn(actor_params, o, k, eps, actor_policy)
+        with jax.named_scope("env"):
+            est, nxt, r, d, tr, fo = jax.vmap(env.step)(est, a)
         return (est, nxt), (o, a, r, d, tr, fo)
 
     keys = jax.random.split(key, n_steps)

@@ -176,7 +176,9 @@ def minibatch_epochs(key, params, opt_state, batch, apply_fn, cfg,
                      optimizer_step, loss_fn=ppo_loss, grad_mask=None,
                      dist: Optional[ActionDist] = None):
     """Standard PPO epochs x minibatches loop (python loop: trace-time
-    constants, jit the caller)."""
+    constants, jit the caller).  The permutation and gather run under
+    the named scope ``shuffle``, the loss forward and backward under
+    ``grad``; ``optimizer_step`` names its own."""
     n = batch["obs"].shape[0]
     if n % cfg.minibatches != 0:
         raise ValueError(
@@ -191,15 +193,18 @@ def minibatch_epochs(key, params, opt_state, batch, apply_fn, cfg,
     # is supplied (custom losses need not know about ActionDist)
     extra = () if dist is None else (dist,)
     for _ in range(cfg.epochs):
-        key, sub = jax.random.split(key)
-        perm = jax.random.permutation(sub, n)
+        with jax.named_scope("shuffle"):
+            key, sub = jax.random.split(key)
+            perm = jax.random.permutation(sub, n)
         for i in range(cfg.minibatches):
-            idx = jax.lax.dynamic_slice_in_dim(perm, i * mb, mb)
-            mbatch = {k: v[idx] for k, v in batch.items()}
-            (_, stats), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, apply_fn, mbatch, cfg,
-                                       *extra)
-            if grad_mask is not None:
-                grads = apply_stage_mask(grads, grad_mask)
+            with jax.named_scope("shuffle"):
+                idx = jax.lax.dynamic_slice_in_dim(perm, i * mb, mb)
+                mbatch = {k: v[idx] for k, v in batch.items()}
+            with jax.named_scope("grad"):
+                (_, stats), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params, apply_fn, mbatch,
+                                           cfg, *extra)
+                if grad_mask is not None:
+                    grads = apply_stage_mask(grads, grad_mask)
             params, opt_state = optimizer_step(params, opt_state, grads)
     return params, opt_state, stats

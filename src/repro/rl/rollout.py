@@ -61,28 +61,40 @@ def init_envs(env: Environment, key: Array, n_envs: int, mesh=None):
 def rollout(params, env: Environment, apply_fn: Callable, key: Array,
             env_state, obs, n_steps: int,
             dist: Optional[ActionDist] = None) -> RolloutResult:
-    """Collect ``n_steps`` transitions from every env (scan over time)."""
+    """Collect ``n_steps`` transitions from every env (scan over time).
+
+    Each phase is a named scope (``actor``, ``sample``, ``env``,
+    ``relayout``), so a profiler trace splits the rollout's device
+    time by phase; scopes are op metadata and change no computation.
+    """
     if dist is None:
         dist = distribution_for(env.action_space)
 
     def one(carry, step_key):
         state, obs = carry
-        dparams, value = apply_fn(params, obs)
-        dparams = dparams.astype(jnp.float32)
-        action = dist.sample(step_key, dparams)
-        logp = dist.log_prob(dparams, action)
-        state, next_obs, reward, done, truncated, final_obs = \
-            jax.vmap(env.step)(state, action)
-        tr = Trajectory(_rows(obs), action, logp, value, reward, done,
-                        truncated, _rows(final_obs))
+        with jax.named_scope("actor"):
+            dparams, value = apply_fn(params, obs)
+            dparams = dparams.astype(jnp.float32)
+        with jax.named_scope("sample"):
+            action = dist.sample(step_key, dparams)
+            logp = dist.log_prob(dparams, action)
+        with jax.named_scope("env"):
+            state, next_obs, reward, done, truncated, final_obs = \
+                jax.vmap(env.step)(state, action)
+        with jax.named_scope("relayout"):
+            tr = Trajectory(_rows(obs), action, logp, value, reward,
+                            done, truncated, _rows(final_obs))
         return (state, next_obs), tr
 
-    keys = jax.random.split(key, n_steps)
+    with jax.named_scope("sample"):
+        keys = jax.random.split(key, n_steps)
     (env_state, obs), traj = jax.lax.scan(one, (env_state, obs), keys)
-    shape = (n_steps,) + obs.shape
-    traj = traj._replace(obs=traj.obs.reshape(shape),
-                         next_obs=traj.next_obs.reshape(shape))
-    last_value = apply_fn(params, obs)[1]
+    with jax.named_scope("relayout"):
+        shape = (n_steps,) + obs.shape
+        traj = traj._replace(obs=traj.obs.reshape(shape),
+                             next_obs=traj.next_obs.reshape(shape))
+    with jax.named_scope("actor"):
+        last_value = apply_fn(params, obs)[1]
     return RolloutResult(traj, last_value, env_state, obs)
 
 

@@ -39,6 +39,13 @@ uninstrumented one (docs/observability.md contract; test-asserted).
 With ``metrics=None`` (the default, and what the trace audit lowers)
 signatures and donation contracts are exactly the historical ones
 above.
+
+Phases (``jax.named_scope``): every op of an iteration sits under one
+top-level scope — ``rollout``, ``learner`` and ``metrics``, and for
+the value family ``replay`` too — so each op's ``tf_op`` in a
+profiler trace carries its phase (``rollout/actor``,
+``learner/grad``, ...).  Scopes are op metadata only: fusions,
+numerics and donation are unchanged.
 """
 from __future__ import annotations
 
@@ -63,31 +70,44 @@ from repro.rl.value import (ddpg_actor_loss, ddpg_critic_loss_td,
                             epsilon, nstep_targets, polyak)
 
 
+def _adamw_step(sched, ocfg):
+    """The learner's optimizer step (gradient clip + AdamW update),
+    under the named scope ``adamw``."""
+
+    def opt_step(p, s, g):
+        with jax.named_scope("adamw"):
+            p, s, _ = adamw_update(g, s, p, sched, ocfg)
+        return p, s
+
+    return opt_step
+
+
 def make_onpolicy_iteration(env, apply_fn, a_policy, mesh, dist, pcfg,
                             loss_fn, sched, ocfg, *, rollout_len: int,
                             n_envs: int, n_slots: int, metrics=None):
     """One sharded-collect + minibatch-update step (ppo / a2c)."""
     learner_apply = lambda p, o: apply_fn(p, o, None)  # noqa: E731
+    opt_step = _adamw_step(sched, ocfg)
 
     def update(params, opt, est, obs, packed, key, gmask, alive):
-        k1, k2 = jax.random.split(key)
-        res = collect_sharded(packed, env, apply_fn, a_policy, k1, est,
-                              obs, rollout_len, mesh, dist)
-        mask = fleet_mask(alive, n_envs // n_slots)
-        # the learner's fp32 value head prices the truncation bootstrap
-        batch = batch_from_traj(res.traj, res.last_value, pcfg,
-                                actor_mask=mask,
-                                value_fn=lambda o: learner_apply(params,
-                                                                 o)[1])
-
-        def opt_step(p, s, g):
-            p, s, _ = adamw_update(g, s, p, sched, ocfg)
-            return p, s
-
-        params, opt, stats = minibatch_epochs(
-            k2, params, opt, batch, learner_apply, pcfg, opt_step,
-            loss_fn=loss_fn, grad_mask=gmask, dist=dist)
-        ret, n_ep = episode_returns(res.traj)
+        with jax.named_scope("rollout"):
+            # the split also makes the learner's key: two scalar ops
+            k1, k2 = jax.random.split(key)
+            res = collect_sharded(packed, env, apply_fn, a_policy, k1,
+                                  est, obs, rollout_len, mesh, dist)
+        with jax.named_scope("learner"):
+            with jax.named_scope("advantage"):
+                mask = fleet_mask(alive, n_envs // n_slots)
+                # the learner's fp32 value head prices the truncation
+                # bootstrap
+                batch = batch_from_traj(
+                    res.traj, res.last_value, pcfg, actor_mask=mask,
+                    value_fn=lambda o: learner_apply(params, o)[1])
+            params, opt, stats = minibatch_epochs(
+                k2, params, opt, batch, learner_apply, pcfg, opt_step,
+                loss_fn=loss_fn, grad_mask=gmask, dist=dist)
+        with jax.named_scope("rollout"), jax.named_scope("returns"):
+            ret, n_ep = episode_returns(res.traj)
         return (params, opt, res.final_env, res.final_obs, ret,
                 n_ep), stats["loss"]
 
@@ -103,12 +123,13 @@ def make_onpolicy_iteration(env, apply_fn, a_policy, mesh, dist, pcfg,
                   mbuf):
         (params, opt, est, obs, ret, n_ep), loss = update(
             params, opt, est, obs, packed, key, gmask, alive)
-        mbuf = counter_add(mbuf, "env_steps", rollout_len * n_envs)
-        mbuf = counter_add(mbuf, "episodes", n_ep)
-        mbuf = gauge_set(mbuf, "return_mean", ret)
-        mbuf = gauge_set(mbuf, "loss", loss)
-        mbuf = gauge_set(mbuf, "alive_frac",
-                         jnp.mean(alive.astype(jnp.float32)))
+        with jax.named_scope("metrics"):
+            mbuf = counter_add(mbuf, "env_steps", rollout_len * n_envs)
+            mbuf = counter_add(mbuf, "episodes", n_ep)
+            mbuf = gauge_set(mbuf, "return_mean", ret)
+            mbuf = gauge_set(mbuf, "loss", loss)
+            mbuf = gauge_set(mbuf, "alive_frac",
+                             jnp.mean(alive.astype(jnp.float32)))
         return params, opt, est, obs, ret, n_ep, mbuf
 
     return iteration
@@ -116,7 +137,8 @@ def make_onpolicy_iteration(env, apply_fn, a_policy, mesh, dist, pcfg,
 
 def _value_metric_updates(mbuf, rb, *, env_steps, n_ep, ret, eps, buf):
     """The value-family metric writes, shared by the single-device and
-    sharded steps (replay_size already sums a slot-leading state)."""
+    sharded steps (replay_size already sums a slot-leading state); the
+    caller opens the ``metrics`` scope."""
     mbuf = counter_add(mbuf, "env_steps", env_steps)
     mbuf = counter_add(mbuf, "episodes", n_ep)
     mbuf = gauge_set(mbuf, "return_mean", ret)
@@ -136,58 +158,67 @@ def make_value_iteration(env, agent, rb, a_policy, sched, ocfg, *,
     ddpg)."""
     cfg = agent.cfg
     discrete = agent.discrete
+    opt_step = _adamw_step(sched, ocfg)
 
     def body(params, target, opt, buf, packed, est, obs, key, it):
-        k_collect, k_update = jax.random.split(key)
-        eps = (epsilon(it * rollout_len, cfg) if discrete
-               else jnp.zeros(()))
-        (est, obs), (O, A, R, D, Tr, FO) = collect_value(
-            packed, env, agent.behave, a_policy, k_collect, est, obs,
-            rollout_len, eps)
+        with jax.named_scope("rollout"):
+            k_collect, k_update = jax.random.split(key)
+            eps = (epsilon(it * rollout_len, cfg) if discrete
+                   else jnp.zeros(()))
+            (est, obs), (O, A, R, D, Tr, FO) = collect_value(
+                packed, env, agent.behave, a_policy, k_collect, est,
+                obs, rollout_len, eps)
+            rets, nxt, disc = nstep_targets(R, D, Tr, FO, cfg.gamma,
+                                            cfg.n_step)
 
-        rets, nxt, disc = nstep_targets(R, D, Tr, FO, cfg.gamma,
-                                        cfg.n_step)
-        T, B = R.shape
-        flat = lambda x: x.reshape((T * B,) + x.shape[2:])  # noqa: E731
-        buf = rb.add(buf, flat(O), flat(A), flat(rets), flat(nxt),
-                     flat(disc))
-
-        # PER bias correction anneals toward full (beta=1) over the
-        # run; uniform ignores it (python literal, compiles away)
-        beta = (per_beta0 + (1.0 - per_beta0)
-                * jnp.clip(it / beta_iters, 0.0, 1.0)
-                if rb.prioritized else 1.0)
-
-        def opt_step(p, s, g):
-            p, s, _ = adamw_update(g, s, p, sched, ocfg)
-            return p, s
+        with jax.named_scope("replay"):
+            T, B = R.shape
+            flat = lambda x: x.reshape((T * B,) + x.shape[2:])  # noqa: E731
+            buf = rb.add(buf, flat(O), flat(A), flat(rets), flat(nxt),
+                         flat(disc))
+            # PER bias correction anneals toward full (beta=1) over
+            # the run; uniform ignores it (python literal, compiles
+            # away)
+            beta = (per_beta0 + (1.0 - per_beta0)
+                    * jnp.clip(it / beta_iters, 0.0, 1.0)
+                    if rb.prioritized else 1.0)
 
         for _ in range(updates_per_iter):
-            k_update, k_s, k_n = jax.random.split(k_update, 3)
-            batch = rb.sample(buf, k_s, cfg.batch_size,
-                              min_size=cfg.learn_start, beta=beta)
-            if algo == "ddpg":
-                g_c, td = jax.grad(ddpg_critic_loss_td, has_aux=True)(
-                    params["critic"], target["critic"], target["actor"],
-                    agent.critic_apply, agent.act, batch, cfg, k_n)
-                c_p, c_s = opt_step(params["critic"], opt["critic"], g_c)
-                g_a = jax.grad(ddpg_actor_loss)(
-                    params["actor"], c_p, agent.critic_apply, agent.act,
-                    batch)
-                a_p, a_s = opt_step(params["actor"], opt["actor"], g_a)
-                params = {"actor": a_p, "critic": c_p}
-                opt = {"actor": a_s, "critic": c_s}
-                target = polyak(target, params, cfg.tau)
-            else:
-                g, td = jax.grad(agent.loss_fn, has_aux=True)(
-                    params, target,
-                    lambda p, o: agent.q_apply(p, o, None), batch, cfg)
-                params, opt = opt_step(params, opt, g)
-                target = polyak(target, params, cfg.target_tau)
-            # priority refresh from the fresh TD errors (uniform: no-op)
-            buf = rb.update(buf, batch["indices"], td)
+            with jax.named_scope("replay"):
+                k_update, k_s, k_n = jax.random.split(k_update, 3)
+                batch = rb.sample(buf, k_s, cfg.batch_size,
+                                  min_size=cfg.learn_start, beta=beta)
+            with jax.named_scope("learner"):
+                if algo == "ddpg":
+                    g_c, td = jax.grad(ddpg_critic_loss_td,
+                                       has_aux=True)(
+                        params["critic"], target["critic"],
+                        target["actor"], agent.critic_apply, agent.act,
+                        batch, cfg, k_n)
+                    c_p, c_s = opt_step(params["critic"],
+                                        opt["critic"], g_c)
+                    g_a = jax.grad(ddpg_actor_loss)(
+                        params["actor"], c_p, agent.critic_apply,
+                        agent.act, batch)
+                    a_p, a_s = opt_step(params["actor"], opt["actor"],
+                                        g_a)
+                    params = {"actor": a_p, "critic": c_p}
+                    opt = {"actor": a_s, "critic": c_s}
+                    target = polyak(target, params, cfg.tau)
+                else:
+                    g, td = jax.grad(agent.loss_fn, has_aux=True)(
+                        params, target,
+                        lambda p, o: agent.q_apply(p, o, None), batch,
+                        cfg)
+                    params, opt = opt_step(params, opt, g)
+                    target = polyak(target, params, cfg.target_tau)
+            with jax.named_scope("replay"):
+                # priority refresh from the fresh TD errors (uniform:
+                # no-op)
+                buf = rb.update(buf, batch["indices"], td)
 
-        ret, n_ep = episode_returns_from(R, D | Tr)
+        with jax.named_scope("rollout"), jax.named_scope("returns"):
+            ret, n_ep = episode_returns_from(R, D | Tr)
         return params, target, opt, buf, est, obs, ret, n_ep
 
     if metrics is None:
@@ -197,13 +228,14 @@ def make_value_iteration(env, agent, rb, a_policy, sched, ocfg, *,
     def iteration(params, target, opt, buf, packed, est, obs, key, it,
                   mbuf):
         n_envs = obs.shape[0]
-        eps = (epsilon(it * rollout_len, cfg) if discrete
-               else jnp.zeros(()))
         params, target, opt, buf, est, obs, ret, n_ep = body(
             params, target, opt, buf, packed, est, obs, key, it)
-        mbuf = _value_metric_updates(
-            mbuf, rb, env_steps=rollout_len * n_envs, n_ep=n_ep,
-            ret=ret, eps=eps, buf=buf)
+        with jax.named_scope("metrics"):
+            eps = (epsilon(it * rollout_len, cfg) if discrete
+                   else jnp.zeros(()))
+            mbuf = _value_metric_updates(
+                mbuf, rb, env_steps=rollout_len * n_envs, n_ep=n_ep,
+                ret=ret, eps=eps, buf=buf)
         return params, target, opt, buf, est, obs, ret, n_ep, mbuf
 
     return iteration
@@ -256,69 +288,80 @@ def make_sharded_value_iteration(env, agent, srb, a_policy, sched, ocfg,
         return jax.tree.map(
             lambda x: jax.lax.psum(x, axes) / n_alive, tree)
 
-    def opt_step(p, s, g):
-        p, s, _ = adamw_update(g, s, p, sched, ocfg)
-        return p, s
+    opt_step = _adamw_step(sched, ocfg)
 
     def update_shard(params, target, opt, buf, trans, key, it, alive):
-        # leading slot axis arrives sharded to size 1: take local views
-        lbuf = jax.tree.map(lambda x: x[0], buf)
-        O, A, rets, nxt, disc = (x[0] for x in trans)
-        a_live = alive[0].astype(jnp.float32)
-        n_alive = jnp.maximum(
-            jax.lax.psum(a_live, axes), 1.0)
+        with jax.named_scope("replay"):
+            # leading slot axis arrives sharded to size 1: take local
+            # views
+            lbuf = jax.tree.map(lambda x: x[0], buf)
+            O, A, rets, nxt, disc = (x[0] for x in trans)
+            a_live = alive[0].astype(jnp.float32)
 
-        idx = jax.lax.axis_index(axes[0])
-        for a in axes[1:]:
-            idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
+            idx = jax.lax.axis_index(axes[0])
+            for a in axes[1:]:
+                idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
 
-        lbuf = rb.add(lbuf, O, A, rets, nxt, disc)
-        # global underfill gate: learn_start counts total transitions
-        size_g = jax.lax.psum(replay_size(lbuf), axes)
-        ok = (size_g >= learn_min).astype(jnp.float32)
+            lbuf = rb.add(lbuf, O, A, rets, nxt, disc)
+            # global underfill gate: learn_start counts total
+            # transitions
+            size_g = jax.lax.psum(replay_size(lbuf), axes)
+            ok = (size_g >= learn_min).astype(jnp.float32)
 
-        beta = (per_beta0 + (1.0 - per_beta0)
-                * jnp.clip(it / beta_iters, 0.0, 1.0)
-                if rb.prioritized else 1.0)
+            beta = (per_beta0 + (1.0 - per_beta0)
+                    * jnp.clip(it / beta_iters, 0.0, 1.0)
+                    if rb.prioritized else 1.0)
+        with jax.named_scope("learner"):
+            n_alive = jnp.maximum(
+                jax.lax.psum(a_live, axes), 1.0)
 
         k_update = key
         for _ in range(updates_per_iter):
-            k_update, k_s, k_n = jax.random.split(k_update, 3)
-            k_s, k_n = slot_key(k_s, idx), slot_key(k_n, idx)
-            batch = rb.sample(lbuf, k_s, n_local, min_size=1, beta=beta)
-            if rb.prioritized:
-                w = per_global_weights(batch["probs"], size_g, beta,
-                                       n_slots)
-                w = normalize_weights(
-                    w, jax.lax.pmax(jnp.max(w), axes))
-                batch["weight"] = w * ok * a_live
-            else:
-                batch["weight"] = jnp.broadcast_to(ok * a_live,
-                                                   (n_local,))
-            if algo == "ddpg":
-                g_c, td = jax.grad(ddpg_critic_loss_td, has_aux=True)(
-                    params["critic"], target["critic"], target["actor"],
-                    agent.critic_apply, agent.act, batch, cfg, k_n)
-                c_p, c_s = opt_step(params["critic"], opt["critic"],
-                                    psum_mean(g_c, n_alive))
-                g_a = jax.grad(ddpg_actor_loss)(
-                    params["actor"], c_p, agent.critic_apply, agent.act,
-                    batch)
-                a_p, a_s = opt_step(params["actor"], opt["actor"],
-                                    psum_mean(g_a, n_alive))
-                params = {"actor": a_p, "critic": c_p}
-                opt = {"actor": a_s, "critic": c_s}
-                target = polyak(target, params, cfg.tau)
-            else:
-                g, td = jax.grad(agent.loss_fn, has_aux=True)(
-                    params, target,
-                    lambda p, o: agent.q_apply(p, o, None), batch, cfg)
-                params, opt = opt_step(params, opt,
-                                       psum_mean(g, n_alive))
-                target = polyak(target, params, cfg.target_tau)
-            lbuf = rb.update(lbuf, batch["indices"], td)
+            with jax.named_scope("replay"):
+                k_update, k_s, k_n = jax.random.split(k_update, 3)
+                k_s, k_n = slot_key(k_s, idx), slot_key(k_n, idx)
+                batch = rb.sample(lbuf, k_s, n_local, min_size=1,
+                                  beta=beta)
+                if rb.prioritized:
+                    w = per_global_weights(batch["probs"], size_g, beta,
+                                           n_slots)
+                    w = normalize_weights(
+                        w, jax.lax.pmax(jnp.max(w), axes))
+                    batch["weight"] = w * ok * a_live
+                else:
+                    batch["weight"] = jnp.broadcast_to(ok * a_live,
+                                                       (n_local,))
+            with jax.named_scope("learner"):
+                if algo == "ddpg":
+                    g_c, td = jax.grad(ddpg_critic_loss_td,
+                                       has_aux=True)(
+                        params["critic"], target["critic"],
+                        target["actor"], agent.critic_apply, agent.act,
+                        batch, cfg, k_n)
+                    c_p, c_s = opt_step(params["critic"],
+                                        opt["critic"],
+                                        psum_mean(g_c, n_alive))
+                    g_a = jax.grad(ddpg_actor_loss)(
+                        params["actor"], c_p, agent.critic_apply,
+                        agent.act, batch)
+                    a_p, a_s = opt_step(params["actor"], opt["actor"],
+                                        psum_mean(g_a, n_alive))
+                    params = {"actor": a_p, "critic": c_p}
+                    opt = {"actor": a_s, "critic": c_s}
+                    target = polyak(target, params, cfg.tau)
+                else:
+                    g, td = jax.grad(agent.loss_fn, has_aux=True)(
+                        params, target,
+                        lambda p, o: agent.q_apply(p, o, None), batch,
+                        cfg)
+                    params, opt = opt_step(params, opt,
+                                           psum_mean(g, n_alive))
+                    target = polyak(target, params, cfg.target_tau)
+            with jax.named_scope("replay"):
+                lbuf = rb.update(lbuf, batch["indices"], td)
 
-        buf = jax.tree.map(lambda x: x[None], lbuf)
+        with jax.named_scope("replay"):
+            buf = jax.tree.map(lambda x: x[None], lbuf)
         return params, target, opt, buf
 
     update_fn = jax.shard_map(
@@ -330,15 +373,16 @@ def make_sharded_value_iteration(env, agent, srb, a_policy, sched, ocfg,
 
     def body(params, target, opt, buf, packed, est, obs, key, it,
              alive):
-        k_collect, k_update = jax.random.split(key)
-        eps = (epsilon(it * rollout_len, cfg) if discrete
-               else jnp.zeros(()))
-        (est, obs), (O, A, R, D, Tr, FO) = collect_value_sharded(
-            packed, env, agent.behave, a_policy, k_collect, est, obs,
-            rollout_len, eps, mesh)
+        with jax.named_scope("rollout"):
+            k_collect, k_update = jax.random.split(key)
+            eps = (epsilon(it * rollout_len, cfg) if discrete
+                   else jnp.zeros(()))
+            (est, obs), (O, A, R, D, Tr, FO) = collect_value_sharded(
+                packed, env, agent.behave, a_policy, k_collect, est,
+                obs, rollout_len, eps, mesh)
+            rets, nxt, disc = nstep_targets(R, D, Tr, FO, cfg.gamma,
+                                            cfg.n_step)
 
-        rets, nxt, disc = nstep_targets(R, D, Tr, FO, cfg.gamma,
-                                        cfg.n_step)
         T, B = R.shape
         Bl = B // n_slots
 
@@ -349,10 +393,13 @@ def make_sharded_value_iteration(env, agent, srb, a_policy, sched, ocfg,
             x = jnp.swapaxes(x, 0, 1)
             return x.reshape((n_slots, T * Bl) + x.shape[3:])
 
-        trans = tuple(slotted(x) for x in (O, A, rets, nxt, disc))
+        with jax.named_scope("replay"):
+            trans = tuple(slotted(x) for x in (O, A, rets, nxt, disc))
+        # update_shard opens its own replay and learner scopes
         params, target, opt, buf = update_fn(params, target, opt, buf,
                                              trans, k_update, it, alive)
-        ret, n_ep = episode_returns_from(R, D | Tr)
+        with jax.named_scope("rollout"), jax.named_scope("returns"):
+            ret, n_ep = episode_returns_from(R, D | Tr)
         return params, target, opt, buf, est, obs, ret, n_ep
 
     if metrics is None:
@@ -362,15 +409,16 @@ def make_sharded_value_iteration(env, agent, srb, a_policy, sched, ocfg,
     def iteration(params, target, opt, buf, packed, est, obs, key, it,
                   alive, mbuf):
         n_envs = obs.shape[0]
-        eps = (epsilon(it * rollout_len, cfg) if discrete
-               else jnp.zeros(()))
         params, target, opt, buf, est, obs, ret, n_ep = body(
             params, target, opt, buf, packed, est, obs, key, it, alive)
-        mbuf = _value_metric_updates(
-            mbuf, srb, env_steps=rollout_len * n_envs, n_ep=n_ep,
-            ret=ret, eps=eps, buf=buf)
-        mbuf = gauge_set(mbuf, "alive_frac",
-                         jnp.mean(alive.astype(jnp.float32)))
+        with jax.named_scope("metrics"):
+            eps = (epsilon(it * rollout_len, cfg) if discrete
+                   else jnp.zeros(()))
+            mbuf = _value_metric_updates(
+                mbuf, srb, env_steps=rollout_len * n_envs, n_ep=n_ep,
+                ret=ret, eps=eps, buf=buf)
+            mbuf = gauge_set(mbuf, "alive_frac",
+                             jnp.mean(alive.astype(jnp.float32)))
         return params, target, opt, buf, est, obs, ret, n_ep, mbuf
 
     return iteration

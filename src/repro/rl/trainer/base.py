@@ -42,7 +42,7 @@ from repro.distributed.sharding import data_axes, data_axis_size
 from repro.launch.mesh import (describe, make_host_mesh,
                                make_production_mesh)
 from repro.obs import (Console, MetricSpec, ProfileWindow,
-                       RunTelemetry, SpanClock, flush)
+                       RunTelemetry, SpanClock, compiles, flush)
 from repro.rl.actor_learner import FleetSync, sync_bytes
 from repro.rl.trainer.state import STATE_SCHEMA, TrainState
 
@@ -125,6 +125,10 @@ class Trainer:
         # the family metric spec, resolved in train() when telemetry
         # is on; None keeps the historical (uninstrumented) programs
         self.metrics: Optional[MetricSpec] = None
+        # host phases: ``train()`` drains them into each step record;
+        # ``pack``/``step`` time ``pack`` and ``dispatch`` on it, so
+        # outside ``train()`` those show only in a profiler trace
+        self.clock = SpanClock("train")
         self.stage_list = [None]
         self.stage_names = ["all"]
 
@@ -254,11 +258,12 @@ class Trainer:
             # contiguous across a restart
             self.metrics = self.metric_spec()
             tel = RunTelemetry(self.metrics_dir, run=self.run_meta(),
-                               start=start)
+                               start=start, clock=self.clock)
         prof = (ProfileWindow(self.profile_dir, self.profile_start,
                               self.profile_steps)
                 if self.profile_dir else None)
-        clock = tel.clock if tel else SpanClock()
+        clock = self.clock
+        clock.drain()
         iteration = self.build_iteration()
         mbuf = self.metrics.init() if self.metrics else None
         sync = FleetSync(self.n_slots, max_lag=self.max_lag)
@@ -270,6 +275,7 @@ class Trainer:
         w_payload = w_fp32 = 0
         t0 = time.time()
         t_win = time.perf_counter()
+        c_win = compiles.LOG.backend_compiles
         for si, stage in enumerate(self.stage_list):
             ctx = self.stage_setup(state, stage)
             for it in range(self.iters):
@@ -283,7 +289,7 @@ class Trainer:
                             tel.profile(prof.dir, win)
                         con.info(f"profiler trace for steps "
                                  f"[{win[0]}, {win[1]}] -> {prof.dir}")
-                with clock("sync"):
+                with clock("sync", g=g):
                     sync.push(self.pack(state))
                     stale = sync.fetch(self.fetch_lag)
                 payload, fp32_eq = sync_bytes(stale)
@@ -294,7 +300,7 @@ class Trainer:
                 # split: a resumed run at step g draws the same stream
                 # the uninterrupted run would have
                 sub = jax.random.fold_in(self.key, g)
-                with clock("step"):
+                with clock("step", g=g):
                     if mbuf is not None:
                         state, ret, n_ep, mbuf = self.step(
                             iteration, state, stale, sub, g, ctx,
@@ -322,6 +328,9 @@ class Trainer:
                     metrics.update(self.host_metrics(state, metrics))
                     metrics["sync_payload_bytes"] = w_payload
                     metrics["sync_fp32_bytes"] = w_fp32
+                    c_now = compiles.LOG.backend_compiles
+                    metrics["compiles"] = c_now - c_win
+                    c_win = c_now
                     metrics["staleness_max"] = int(
                         jax.device_get(sync.staleness()).max())
                     metrics.setdefault(
@@ -338,7 +347,7 @@ class Trainer:
                     w_payload = w_fp32 = 0
                     t_win = time.perf_counter()
                 if mgr and mgr.should_save(g):
-                    with clock("checkpoint"):
+                    with clock("checkpoint", g=g):
                         mgr.save(g, state,
                                  metadata={**self.metadata(it, stage),
                                            "schema": STATE_SCHEMA})

@@ -166,19 +166,21 @@ class OnPolicyTrainer(Trainer):
         return meta
 
     def pack(self, state):
-        return pack_weights(state.params, self.comm)
+        with self.clock("pack"):
+            return pack_weights(state.params, self.comm)
 
     def step(self, iteration, state, packed, key, g, stage_ctx, alive,
              mbuf=None):
         args = (state.params, state.opt, state.est, state.obs, packed,
                 key, stage_ctx, alive)
         if mbuf is not None:
-            params, opt, est, obs, ret, n_ep, mbuf = iteration(*args,
-                                                               mbuf)
-            return onpolicy_state(params, opt, est, obs), ret, n_ep, \
-                mbuf
-        params, opt, est, obs, ret, n_ep = iteration(*args)
-        return onpolicy_state(params, opt, est, obs), ret, n_ep
+            args = args + (mbuf,)
+        with self.clock("dispatch", g=g):
+            out = iteration(*args)
+        params, opt, est, obs, ret, n_ep = out[:6]
+        new = onpolicy_state(params, opt, est, obs)
+        return ((new, ret, n_ep) if mbuf is None
+                else (new, ret, n_ep, out[6]))
 
     def stage_setup(self, state, stage):
         # the stage grad-mask actually freezes the off-stage subtree

@@ -207,8 +207,9 @@ class ValueTrainer(Trainer):
     def pack(self, state):
         # only the behaviour net ships to the fleet (ddpg: the actor
         # alone — syncing the twin critics would triple the payload)
-        return pack_weights(self.agent.behaviour_subtree(state.params),
-                            self.comm)
+        with self.clock("pack"):
+            return pack_weights(
+                self.agent.behaviour_subtree(state.params), self.comm)
 
     def step(self, iteration, state, packed, key, g, stage_ctx, alive,
              mbuf=None):
@@ -218,7 +219,8 @@ class ValueTrainer(Trainer):
             args = args + (alive,)
         if mbuf is not None:
             args = args + (mbuf,)
-        out = iteration(*args)
+        with self.clock("dispatch", g=g):
+            out = iteration(*args)
         p, t, o, b, est, obs, ret, n_ep = out[:8]
         new = TrainState(p, t, o, b, est, obs)
         return ((new, ret, n_ep) if mbuf is None

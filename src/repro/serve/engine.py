@@ -241,7 +241,7 @@ def serve_episodes(server: PolicyServer, episodes: int,
     jax.block_until_ready(step_fn(est, server.act(obs)))
     server.reset_stats()
 
-    clock = SpanClock()
+    clock = SpanClock("serve")
     prev_r = 0
     prev_inf = 0.0
     prev_counts = np.array(server._latency.counts)

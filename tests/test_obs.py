@@ -417,3 +417,150 @@ def test_obs_summary_cli_renders_and_validates(tmp_path, capsys):
                             "t_wall": 0.0}) + "\n")
     assert cli.main([bad, "--validate"]) == 1
     assert "INVALID" in capsys.readouterr().err
+
+
+# ---------------------------------------------------------------------------
+# phases on the profiler's clock: device scopes, host spans, compiles
+# ---------------------------------------------------------------------------
+
+
+class _Lowered(Exception):
+    pass
+
+
+def _iteration_op_names(trainer):
+    """The ``op_name`` metadata of the trainer's jitted iteration with
+    its metric buffer, compiled at the trainer's (tiny) size; the
+    arguments are the ones ``Trainer.step`` passes."""
+    import re
+
+    from repro.rl.actor_learner import FleetSync
+
+    trainer.metrics = trainer.metric_spec()
+    state = trainer.place(trainer.init_state())
+    iteration = trainer.build_iteration()
+    text = {}
+
+    def lower(*args):
+        text["hlo"] = iteration.lower(*args).compile().as_text()
+        raise _Lowered
+
+    with pytest.raises(_Lowered):
+        trainer.step(lower, state, trainer.pack(state),
+                     jax.random.PRNGKey(0), 0, None,
+                     FleetSync(trainer.n_slots).alive(),
+                     trainer.metrics.init())
+    return set(re.findall(r'op_name="([^"]*)"', text["hlo"]))
+
+
+def _onpolicy_trainer():
+    from repro.rl.trainer.onpolicy import OnPolicyTrainer
+    return OnPolicyTrainer(env_name="cartpole", iters=1, n_envs=8,
+                           rollout_len=4, verbose=False, mesh_devices=1)
+
+
+def _value_trainer(sharded):
+    from repro.rl.trainer.value import ValueTrainer
+    mesh = dict(mesh_kind="host", mesh_devices=1) if sharded else {}
+    return ValueTrainer("dqn", "cartpole", iters=1, n_envs=8,
+                        rollout_len=4, verbose=False, replay="per",
+                        replay_capacity=64, updates_per_iter=1, **mesh)
+
+
+@pytest.mark.parametrize("family", ["onpolicy", "value", "sharded_value"])
+def test_iteration_ops_sit_under_phase_scopes(family):
+    """Every op of the jitted iteration carries its phase: the first
+    component after ``jit(iteration)`` is a top-level scope, and the
+    phases a metric or a reading splits on are all there."""
+    if family == "onpolicy":
+        trainer = _onpolicy_trainer()
+        tops = {"rollout", "learner", "metrics"}
+        # (cartpole's observations are rows already: its relayout
+        # compiles away)
+        want = ["rollout/actor", "rollout/sample", "rollout/env",
+                "rollout/returns", "learner/advantage", "learner/shuffle", "learner/grad",
+                "learner/adamw", "metrics"]
+    else:
+        trainer = _value_trainer(family == "sharded_value")
+        tops = {"rollout", "replay", "learner", "metrics"}
+        want = ["rollout/actor", "rollout/env", "rollout/returns",
+                "replay", "learner/adamw", "metrics"]
+    names = [n.split("/")[1:] for n in _iteration_op_names(trainer)
+             if n.startswith("jit(iteration)/")]
+    assert names
+    assert {p[0] for p in names} == tops
+    for scope in want:
+        top, *sub = scope.split("/")
+        assert any(p[0] == top and set(sub) <= set(p) for p in names), \
+            scope
+
+
+def test_trainer_host_spans_on_profiler_clock(tmp_path):
+    """Two iterations of ``Trainer.train`` under a profiler trace: the
+    trace holds the program's ``train.pack`` and ``train.dispatch``
+    spans inside ``train.sync`` and ``train.step``, the step record
+    drains them as nested phases, and each window's record counts the
+    compiles made in it."""
+    from jax.profiler import ProfileData
+
+    from repro.rl.trainer import rl_train
+
+    from repro.obs import compiles
+
+    prof, m = str(tmp_path / "prof"), str(tmp_path / "metrics")
+    n0 = compiles.LOG.backend_compiles
+    rl_train("cartpole", iters=4, n_envs=8, rollout_len=4,
+             verbose=False, seed=3, log_every=2, metrics_dir=m,
+             profile_dir=prof, profile_start=1, profile_steps=2)
+    found = []
+    for root, _, files in os.walk(prof):
+        found += [os.path.join(root, f) for f in files
+                  if f.endswith(".xplane.pb")]
+    (path,) = found
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("train."):
+                    spans.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats)))
+    assert len(spans["train.pack"]) >= 2
+    assert len(spans["train.dispatch"]) >= 2
+    assert {s[2]["g"] for s in spans["train.dispatch"]} >= {1, 2}
+
+    def inside(inner, outer):
+        return all(any(a <= b0 and b1 <= b for a, b, _ in spans[outer])
+                   for b0, b1, _ in spans[inner])
+
+    assert inside("train.pack", "train.sync")
+    assert inside("train.dispatch", "train.step")
+
+    steps = _step_records(os.path.join(m, "train.jsonl"))
+    for r in steps:
+        assert {"sync", "step", "sync/pack", "step/dispatch"} <= set(
+            r["spans"])
+        assert r["spans"]["step/dispatch"] <= r["spans"]["step"]
+    # the first window compiles the iteration; the last compiles nothing
+    per_window = [r["metrics"]["compiles"] for r in steps]
+    assert per_window[0] >= 1 and per_window[-1] == 0
+    assert sum(per_window) <= compiles.LOG.backend_compiles - n0
+
+
+def test_compile_counter_records_one_backend_compile():
+    from repro.obs import compiles
+
+    def fresh_fn(x):
+        return x * 3.0 + 1.0
+
+    f = jax.jit(fresh_fn)
+    x = jnp.ones(5)
+    n0 = compiles.LOG.backend_compiles
+    f(x).block_until_ready()
+    assert compiles.LOG.backend_compiles == n0 + 1
+    mine = [e for e in compiles.LOG.events()
+            if e.fun_name in ("fresh_fn", "jit(fresh_fn)")]
+    assert {e.event for e in mine} == set(compiles.EVENTS)
+    assert all(e.start <= e.end for e in mine)
+    f(x).block_until_ready()
+    assert compiles.LOG.backend_compiles == n0 + 1
