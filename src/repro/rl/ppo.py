@@ -172,13 +172,26 @@ def apply_stage_mask(grads, mask):
     return jax.tree.map(lambda g, m: g * m, grads, mask)
 
 
+def _take_rows(v: Array, idx: Array) -> Array:
+    """``v[idx]`` taken as whole flat samples: gather rows of the
+    [N, prod(...)] view, then give them back the leaf's own shape."""
+    return v.reshape(v.shape[0], -1)[idx].reshape(idx.shape + v.shape[1:])
+
+
 def minibatch_epochs(key, params, opt_state, batch, apply_fn, cfg,
                      optimizer_step, loss_fn=ppo_loss, grad_mask=None,
                      dist: Optional[ActionDist] = None):
     """Standard PPO epochs x minibatches loop (python loop: trace-time
     constants, jit the caller).  The permutation and gather run under
     the named scope ``shuffle``, the loss forward and backward under
-    ``grad``; ``optimizer_step`` names its own."""
+    ``grad``; ``optimizer_step`` names its own.
+
+    Each minibatch gathers whole samples as flat, sample-major rows and
+    takes the leaf's shape only after the gather.  Gathered as images,
+    a conv's batch-minor input layout (3 channels leave the lanes to
+    the batch) is pushed back onto the whole buffer, and every gather
+    becomes a lane gather; as rows, it reads the rollout's own
+    [T*B, H*W*C] buffer and the relayout is paid per minibatch."""
     n = batch["obs"].shape[0]
     if n % cfg.minibatches != 0:
         raise ValueError(
@@ -199,7 +212,8 @@ def minibatch_epochs(key, params, opt_state, batch, apply_fn, cfg,
         for i in range(cfg.minibatches):
             with jax.named_scope("shuffle"):
                 idx = jax.lax.dynamic_slice_in_dim(perm, i * mb, mb)
-                mbatch = {k: v[idx] for k, v in batch.items()}
+                mbatch = {k: _take_rows(v, idx)
+                          for k, v in batch.items()}
             with jax.named_scope("grad"):
                 (_, stats), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params, apply_fn, mbatch,

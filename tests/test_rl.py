@@ -285,6 +285,76 @@ def test_minibatch_epochs_rejects_indivisible_batch():
     assert len(out) == 3
 
 
+def _minibatch_epochs_image_gather(key, params, opt_state, batch,
+                                   apply_fn, cfg, optimizer_step,
+                                   loss_fn):
+    """The loop as it gathered before flat rows: ``v[idx]`` on each
+    leaf's own shape, images included."""
+    n = batch["obs"].shape[0]
+    mb = n // cfg.minibatches
+    stats = None
+    for _ in range(cfg.epochs):
+        key, sub = jax.random.split(key)
+        perm = jax.random.permutation(sub, n)
+        for i in range(cfg.minibatches):
+            idx = jax.lax.dynamic_slice_in_dim(perm, i * mb, mb)
+            mbatch = {k: v[idx] for k, v in batch.items()}
+            (_, stats), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, apply_fn, mbatch, cfg)
+            params, opt_state = optimizer_step(params, opt_state, grads)
+    return params, opt_state, stats
+
+
+@pytest.mark.parametrize("loss_fn", [ppo_loss, a2c_loss],
+                         ids=["ppo", "a2c"])
+@pytest.mark.parametrize("obs_shape", [(8, 8, 3), (4,)],
+                         ids=["image", "rank2"])
+def test_minibatch_epochs_row_gather_is_bit_identical(loss_fn, obs_shape):
+    """Gathering each minibatch as flat sample-major rows puts the same
+    samples in the same minibatch as gathering the leaf as it is:
+    params, optimizer state and stats agree bit for bit."""
+    from repro.optim import AdamWConfig, adamw_init, adamw_update, constant
+    from repro.rl.nets import conv_ac_apply, conv_ac_init
+    n, key = 64, jax.random.PRNGKey(7)
+    ks = jax.random.split(key, 6)
+    if len(obs_shape) == 3:
+        params = unbox(conv_ac_init(ks[0], obs_shape, 3,
+                                    channels=(4, 8), hidden=16))
+        fn = conv_ac_apply
+    else:
+        params = unbox(mlp_ac_init(ks[0], obs_shape[0], 3))
+        fn = mlp_ac_apply
+    batch = {
+        "obs": jax.random.normal(ks[1], (n,) + obs_shape),
+        "actions": jax.random.randint(ks[2], (n,), 0, 3),
+        "log_probs": -jax.random.uniform(ks[3], (n,), minval=0.5,
+                                         maxval=1.5),
+        "advantages": jax.random.normal(ks[4], (n,)),
+        "returns": jax.random.normal(ks[5], (n,)),
+    }
+    cfg = PPOConfig(epochs=2, minibatches=4)
+    sched, ocfg = constant(1e-2), AdamWConfig(max_grad_norm=0.5)
+
+    def opt_step(p, s, g):
+        p, s, _ = adamw_update(g, s, p, sched, ocfg)
+        return p, s
+
+    opt = adamw_init(params)
+    got = jax.jit(lambda k, p, o, b: minibatch_epochs(
+        k, p, o, b, fn, cfg, opt_step, loss_fn=loss_fn))(
+            key, params, opt, batch)
+    want = jax.jit(lambda k, p, o, b: _minibatch_epochs_image_gather(
+        k, p, o, b, fn, cfg, opt_step, loss_fn))(key, params, opt, batch)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                    strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the update moved the weights, so equal is not trivially equal
+    assert any(not np.array_equal(np.asarray(a), np.asarray(b))
+               for a, b in zip(jax.tree.leaves(params),
+                               jax.tree.leaves(got[0]), strict=True))
+
+
 def test_a2c_loss_finite():
     params = unbox(mlp_ac_init(jax.random.PRNGKey(0), 4, 2))
     fn = lambda p, o: mlp_ac_apply(p, o)

@@ -13,6 +13,9 @@ The topology is described inside a fixture (never at import): one
 process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -23,6 +26,10 @@ from repro.kernels.qconv import ops as qconv_ops
 from repro.kernels.qlstm import ops as qlstm_ops
 from repro.kernels.qmac import ops as qmac_ops
 from repro.kernels.vact import ops as vact_ops
+from repro.models import hrl
+from repro.nn.module import unbox
+from repro.optim import AdamWConfig, adamw_init, adamw_update, constant
+from repro.rl.ppo import PPOConfig, minibatch_epochs
 
 FLEET = 512
 
@@ -133,3 +140,68 @@ def test_qlstm_cell_compiles(one_chip):
         ((din, 4 * h), jnp.int8), ((1, 4 * h), jnp.float32),
         ((h, 4 * h), jnp.int8), ((1, 4 * h), jnp.float32),
         ((4 * h,), jnp.float32), ((FLEET, h), jnp.float32))
+
+
+_DEF = re.compile(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\{([\d,]*)")
+_GATHER = re.compile(r"gather\(%([^,\s)]+).*start_index_map=\{(\d+)\}"
+                     r".*op_name=\"([^\"]*)\"")
+
+
+def _shuffle_gathers(text):
+    """(operand dims, operand minor-to-major, sample axis) of every
+    gather under the named scope ``shuffle`` in compiled HLO text."""
+    defs = {m.group(1): (m.group(2), m.group(3))
+            for m in map(_DEF.match, text.splitlines()) if m}
+    out = []
+    for line in text.splitlines():
+        m = _GATHER.search(line) if " gather(" in line else None
+        if m and "shuffle" in m.group(3).split("/"):
+            dims, layout = defs[m.group(1)]
+            out.append((tuple(int(d) for d in dims.split(",")),
+                        tuple(int(d) for d in layout.split(",")),
+                        int(m.group(2))))
+    return out
+
+
+def test_minibatch_gather_reads_sample_major_rows(one_chip):
+    """The learner's minibatch gather reads whole samples, sample axis
+    major, at the E2HRL observation shape.  The batch comes in as the
+    rollout hands it over, one flat row per sample reshaped to images,
+    so layout assignment is free to lay it out: gathered as images, the
+    first conv's batch-minor input layout is pushed back onto the whole
+    buffer and each gather reads its rows along the lanes."""
+    n, cfg = 8192, PPOConfig(epochs=1, minibatches=4)
+    params = unbox(hrl.init(jax.random.PRNGKey(0), CONFIG))
+    sched, ocfg = constant(1e-3), AdamWConfig(max_grad_norm=0.5)
+
+    def apply_fn(p, obs):
+        logits, value, _ = hrl.apply(p, obs, CONFIG)
+        return logits, value
+
+    def opt_step(p, s, g):
+        p, s, _ = adamw_update(g, s, p, sched, ocfg)
+        return p, s
+
+    def learner(key, params, opt, rows, actions, log_probs, advantages,
+                returns):
+        batch = {"obs": rows.reshape((n,) + CONFIG.obs_shape),
+                 "actions": actions, "log_probs": log_probs,
+                 "advantages": advantages, "returns": returns}
+        return minibatch_epochs(key, params, opt, batch, apply_fn, cfg,
+                                opt_step)
+
+    place = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, x.dtype, sharding=one_chip)
+    args = (place(jax.eval_shape(lambda: jax.random.PRNGKey(0))),
+            jax.tree.map(place, params),
+            jax.tree.map(place, jax.eval_shape(adamw_init, params)),
+            place(jax.ShapeDtypeStruct((n, math.prod(CONFIG.obs_shape)),
+                                       jnp.float32)),
+            place(jax.ShapeDtypeStruct((n,), jnp.int32)),
+            *[place(jax.ShapeDtypeStruct((n,), jnp.float32))] * 3)
+    text = jax.jit(learner).lower(*args).compile().as_text()
+    gathers = _shuffle_gathers(text)
+    wide = [g for g in gathers if len(g[0]) > 1]
+    assert len(gathers) == 5 * cfg.minibatches and wide, gathers
+    lane = [g for g in wide if g[1][0] == g[2]]
+    assert not lane, f"gathers along the sample-minor axis: {lane}"
