@@ -11,11 +11,16 @@ the optimizer's first moment after the first and the weights after the
 last are what the configuration's plain reference is compared with,
 once the window has closed and the program's state is freed.
 
-The traffic file gives ``algo``, ``epochs``, ``minibatches``,
-``n_envs``, ``rollout_len``, ``compared_steps`` and ``trace_iters``.
+The configuration names the program's agent (``agent``, and ``net``
+where it gives one, as ``make_agent`` takes them) and states the
+loss's settings (``ppo``) and the optimizer's (``optimizer``); the
+traffic file gives ``algo``, ``epochs``, ``minibatches``, ``n_envs``,
+``rollout_len``, ``compared_steps`` and ``trace_iters``.  The trainer
+is built from these, and refused where it still departs from them.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import gc
 import time
@@ -30,22 +35,16 @@ from harness import (Cell, Outcome, Spans, device_info, peaks, traced,
 
 def _check_states(trainer, cell: Cell, params0) -> None:
     """Refuse a run in which the program would depart from what the
-    configuration and the traffic state."""
-    cfg, job = cell.config, cell.traffic
-    want = {"epochs": job["epochs"], "minibatches": job["minibatches"],
-            **cfg["ppo"]}
-    have = {k: getattr(trainer.pcfg, k) for k in want}
-    want.update({k: cfg["optimizer"][k] for k in
-                 ("b1", "b2", "eps", "weight_decay", "max_grad_norm")})
-    have.update({k: getattr(trainer.ocfg, k) for k in
-                 ("b1", "b2", "eps", "weight_decay", "max_grad_norm")})
+    configuration states.  The ``ppo`` and ``optimizer`` blocks and the
+    traffic's epochs and minibatches are set on the trainer by
+    ``make_trainer``; what the trainer takes otherwise is checked here."""
+    cfg = cell.config
     pol = trainer.a_policy
-    want.update(actor_w_bits=cfg["actor_w_bits"],
+    want = dict(actor_w_bits=cfg["actor_w_bits"],
                 actor_a_bits=cfg["actor_a_bits"],
                 comm_bits=cfg["comm_bits"], lr=cfg["optimizer"]["lr"])
-    have.update(actor_w_bits=pol.w_bits, actor_a_bits=pol.a_bits,
-                comm_bits=trainer.comm,
-                lr=float(trainer.sched(1)))
+    have = dict(actor_w_bits=pol.w_bits, actor_a_bits=pol.a_bits,
+                comm_bits=trainer.comm, lr=float(trainer.sched(1)))
     # the learner's matmuls run at the platform's default precision,
     # as the configuration states, unless JAX is told otherwise
     want["matmul_precision"] = cfg["learner_matmul_precision"]
@@ -63,19 +62,38 @@ def _check_states(trainer, cell: Cell, params0) -> None:
                          f"(have, stated): {bad}")
 
 
+def make_trainer(cell: Cell, seed: int, chips: int):
+    """The program's trainer for the agent the configuration names,
+    with the loss and optimizer settings that the configuration and the
+    traffic state; ``build_iteration`` reads them when it builds."""
+    from repro.rl.trainer.onpolicy import OnPolicyTrainer
+    cfg, job = cell.config, cell.traffic
+    if "agent" not in cfg:
+        raise ValueError(f"configuration {cell.config_name!r} names no "
+                         f"'agent' for the program to build")
+    agent = {k: cfg[k] for k in ("agent", "net") if k in cfg}
+    trainer = OnPolicyTrainer(
+        env_name=cfg["env"], iters=1, n_envs=job["n_envs"],
+        rollout_len=job["rollout_len"], actor_policy=cfg["actor_policy"],
+        lr=cfg["optimizer"]["lr"], comm_bits=cfg["comm_bits"], max_lag=1,
+        seed=seed, mesh_kind="host", mesh_devices=chips, verbose=False,
+        algo=job["algo"], **agent)
+    trainer.pcfg = dataclasses.replace(
+        trainer.pcfg, epochs=job["epochs"], minibatches=job["minibatches"],
+        **cfg["ppo"])
+    trainer.ocfg = dataclasses.replace(
+        trainer.ocfg, **{k: v for k, v in cfg["optimizer"].items()
+                         if k != "lr"})
+    return trainer
+
+
 def build(cell: Cell, seed: int, chips: int,
           patch: Optional[Callable] = None):
     """The trainer with the benchmark's weights, its placed state and
     its jitted iteration; ``patch(trainer)`` may replace a part of the
     program before the iteration is built (the fault tests do)."""
-    from repro.rl.trainer.onpolicy import OnPolicyTrainer
-    cfg, job = cell.config, cell.traffic
-    trainer = OnPolicyTrainer(
-        env_name=cfg["env"], agent="hrl", iters=1, n_envs=job["n_envs"],
-        rollout_len=job["rollout_len"], actor_policy=cfg["actor_policy"],
-        lr=cfg["optimizer"]["lr"], comm_bits=cfg["comm_bits"], max_lag=1,
-        seed=seed, mesh_kind="host", mesh_devices=chips, verbose=False,
-        algo=job["algo"])
+    cfg = cell.config
+    trainer = make_trainer(cell, seed, chips)
     ref = cell.reference()
     params0 = jax.jit(functools.partial(ref.init_params, cfg=cfg))(
         jax.random.PRNGKey(seed))
@@ -189,7 +207,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devs,
         per_layer_ctx=ctx, device=device, breakdown=breakdown)
 
 
-SIDES = ("program", "control", "half_batch", "altered")
+SIDES = ("program", "control", "half_batch", "altered", "unchanged")
+# the size at which the CPU tests run a cell of this driver
+TINY = {"n_envs": 64, "rollout_len": 16}
 
 
 def reading(cell: Cell, seed: int, side: str, devs) -> dict:

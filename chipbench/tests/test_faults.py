@@ -1,15 +1,15 @@
 """A run whose timed path is broken underneath comes out not correct.
 
-Each training cell is run at a small size on the CPU past the
-harness's look for a chip, once for each fault its compared numbers
-catch (``CAUGHT``): a step that leaves its state unchanged, a loss
-taken over half of each minibatch, an action altered where the actor
-samples it; its control (the reference in the nearest precision below
-the configuration's, put in the program's place) comes out not correct
-where a compared number catches it.  ``e2hrl_ppo`` compares
-``delta_gap`` alone (see PERF.md): its other faults and its control are
-checked to read wider than the program on the numbers that have no
-limit yet.
+Each cell whose driver offers the control and the planted faults
+(``SIDES``) is run at a small size on the CPU past the harness's look
+for a chip, once for each fault that its limits file lists under
+``caught``: a step that leaves its state unchanged, a loss taken over
+half of each minibatch, an action altered where the actor samples it;
+its control (the reference in the nearest precision below the
+configuration's, put in the program's place) comes out not correct
+where ``caught`` lists it.  Where it does not, the control and the
+half-batch loss are checked to read wider than the program on the
+second moment, which has no limit.
 """
 import dataclasses
 
@@ -19,16 +19,13 @@ import pytest
 import harness
 import tiny
 
-TRAIN = [w for w in tiny.workloads()
-         if tiny.cell(w).driver == "train_onpolicy"]
+TRAIN = tiny.offering("SIDES")
 FAULTS = ("unchanged", "half_batch", "altered")
-# The sides each cell's compared numbers catch; a cell not listed here
-# catches the control and every fault.
-CAUGHT = {"e2hrl_ppo": ("unchanged",)}
 
 
 def caught(name):
-    return CAUGHT.get(name, FAULTS + ("control",))
+    """The sides that the cell's limits catch, from its limits file."""
+    return harness.load_cell(name).limits["caught"]
 
 
 def unchanged(monkeypatch):

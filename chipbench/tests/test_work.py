@@ -9,21 +9,22 @@ import pytest
 
 import harness
 
-FORWARDS = {
-    "e2hrl_fc": lambda ref, p, x, cfg: ref.learner_apply(p, x, cfg),
-}
+CONFIGS = sorted(c["name"] for c in harness.load_json(
+    harness.ROOT / "BENCHMARK.json")["configs"])
 ELEMENTWISE_SHARE = 0.02   # bias adds, ReLUs, tanh
 
 
-@pytest.mark.parametrize("name", sorted(FORWARDS))
+@pytest.mark.parametrize("name", CONFIGS)
 def test_forward_count_matches_xla(name):
+    """Each reference's ``learner_apply`` is the forward that its work
+    file's ``forward_macs`` counts."""
     ref = harness.import_file(harness.BENCH / "configs" / f"{name}.py")
     work = harness.import_file(harness.BENCH / "work" / f"{name}.py")
     cfg = ref.load()
     params = ref.init_params(jax.random.PRNGKey(0), cfg)
     batch = 16
     x = jnp.zeros((batch,) + tuple(cfg["obs_shape"]))
-    fwd = functools.partial(FORWARDS[name], ref, cfg=cfg)
+    fwd = functools.partial(ref.learner_apply, cfg=cfg)
     ca = jax.jit(fwd).lower(params, x).compile().cost_analysis()
     ca = ca[0] if isinstance(ca, list) else ca
     ours = 2 * work.forward_macs(cfg) * batch

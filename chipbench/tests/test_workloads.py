@@ -56,6 +56,11 @@ def test_cell_files(name):
     assert (harness.BENCH / "configs" / f"{cell.config_name}.py").is_file()
     assert (harness.BENCH / "work" / f"{cell.config_name}.py").is_file()
     assert cell.limits["limits"]
+    sides = set(getattr(tiny.driver(cell), "SIDES", ())) - {"program"}
+    # the control and every fault fail one of the cell's numbers, or
+    # PERF.md says why not: a cell's limits catch at least one side
+    assert cell.limits["caught"]
+    assert set(cell.limits["caught"]) <= sides
     reported = {m["name"] for m in cell.end_to_end}
     assert "setup_s" in reported and len(reported) >= 2
     assert cell.per_layer
